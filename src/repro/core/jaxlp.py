@@ -277,18 +277,26 @@ class JaxRoutingSolver:
 
     # ---- linear operators on the pod tensor ---------------------------------
 
+    # DEFAULT matmul precision on a TPU runs f32 operands as one bf16 pass;
+    # the f32 operators ask for full f32 explicitly so the contract holds on
+    # every backend (the CPU computes f32 either way).
+    _F32 = jax.lax.Precision.HIGHEST
+
     def _util_f32(self, f3, d3, ic):
         """U[t, a, b] = capacity-normalized load of edge (a, b) under TM t —
         always in f32 (the certificate / reported-objective path)."""
-        load1 = jnp.einsum("mij,ijk->mik", d3, f3)  # first hops (+ direct)
-        load2 = jnp.einsum("mij,ijk->mkj", d3, f3 * self.mask_kj[None])
+        load1 = jnp.einsum("mij,ijk->mik", d3, f3,  # first hops (+ direct)
+                           precision=self._F32)
+        load2 = jnp.einsum("mij,ijk->mkj", d3, f3 * self.mask_kj[None],
+                           precision=self._F32)
         return (load1 + load2) * ic[None]
 
     def _util_adj_f32(self, y, d3, ic):
         """Adjoint: y (m, V, V) → gradient on f3 (V, V, V) — always f32."""
         yn = y * ic[None]
-        g1 = jnp.einsum("mij,mik->ijk", d3, yn)
-        g2 = jnp.einsum("mij,mkj->ijk", d3, yn) * self.mask_kj[None]
+        g1 = jnp.einsum("mij,mik->ijk", d3, yn, precision=self._F32)
+        g2 = jnp.einsum("mij,mkj->ijk", d3, yn,
+                        precision=self._F32) * self.mask_kj[None]
         return g1 + g2
 
     def _util(self, f3, d3, ic):

@@ -103,11 +103,10 @@ def shard_leading(fn, mesh: Mesh, repack: bool = False):
     """
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
 
     spec = P(mesh.axis_names[0])
-    sm = shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                   check_rep=False)
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                       check_vma=False)
     if not repack:
         return sm
 
