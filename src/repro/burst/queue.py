@@ -43,6 +43,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.burst.expander import BurstParams, expand
 
 __all__ = ["LossConfig", "link_buffer_gb", "interval_loss",
@@ -129,7 +130,8 @@ def interval_loss(
     if t == 0:
         return np.zeros((0,))
     cap = np.asarray(capacities, dtype=np.float64)
-    sub = expand(demand, cfg.n_sub, cfg.burst, cfg.seed)
+    with obs.span("score.bursts"):
+        sub = expand(demand, cfg.n_sub, cfg.burst, cfg.seed)
     dt = interval_seconds / cfg.n_sub
     buf = link_buffer_gb(cap, cfg.buffer_ms)
     if backend == "numpy":
@@ -137,7 +139,9 @@ def interval_loss(
     else:
         from repro.kernels.queueloss import ops as qlops
 
-        drop, _ = qlops.queue_loss(sub, weights, cap, buf, dt, backend=backend)
+        with obs.span("score.queueloss"):
+            drop, _ = qlops.queue_loss(sub, weights, cap, buf, dt,
+                                       backend=backend)
     return _loss_fractions(drop, sub, t, cfg.n_sub, dt)
 
 

@@ -169,6 +169,14 @@ def _project_simplex_topk(x: jax.Array, valid: jax.Array, k: int) -> jax.Array:
     return out / jnp.maximum(out.sum(), 1e-30)
 
 
+def _fetch(stage: int, *xs) -> list:
+    """Device-to-host copies of ``xs``, the host waiting on the device for
+    PDHG stage ``stage``: a ``jaxlp.wait`` span holds the copies and nothing
+    else."""
+    with obs.span("jaxlp.wait", stage=stage):
+        return [np.asarray(x) for x in xs]
+
+
 @dataclasses.dataclass(eq=False)  # identity hash: each instance owns a jit cache
 class JaxRoutingSolver:
     """Per-(fabric, m) jitted PDHG routing solver.
@@ -798,9 +806,10 @@ class JaxRoutingSolver:
         telemetry in the :meth:`solve_routing_batch` schema, batch length 1);
         ``state`` seeds the next call.
         """
-        d3 = self._dense_tms(tms)[None]
-        ic = self._dense_inv_cap(capacities)[None]
-        valid_b = self._tile_valid(1)
+        with obs.span("jaxlp.warm_inputs"):
+            d3 = self._dense_tms(tms)[None]
+            ic = self._dense_inv_cap(capacities)[None]
+            valid_b = self._tile_valid(1)
 
         def one(x):
             return jnp.asarray(x)[None]
@@ -813,7 +822,7 @@ class JaxRoutingSolver:
                     d3, ic, valid_b, one(anchor_state.f1), one(anchor_state.y1))
         state = RoutingWarmState(f1=f3[0], y1=y1[0])
         u_budget = jnp.asarray(u) * 1.005 + 1e-9
-        stats = {"stage1": self._stage_stats(it1, gap1),
+        stats = {"stage1": self._stage_stats(*_fetch(1, it1, gap1)),
                  "anchor_seconds": 0.0}
         r_star = None
         run2 = hedging and delta > 0
@@ -830,8 +839,9 @@ class JaxRoutingSolver:
                         one(anchor_state.z2))
             f3 = f3r
             state.f2, state.y2, state.z2 = f3r[0], y2[0], z2[0]
-            r_star = float(np.asarray(r)[0])
-            stats["stage2"] = self._stage_stats(it2, gap2,
+            (r,) = _fetch(2, r)
+            r_star = float(r[0])
+            stats["stage2"] = self._stage_stats(*_fetch(2, it2, gap2),
                                                 active=np.asarray([True]))
         if not skip_stage3:
             r_in = jnp.asarray([r_star * 1.005 + 1e-12 if run2 else 1e9],
@@ -847,9 +857,11 @@ class JaxRoutingSolver:
                         d3, ic, valid_b, u_budget, r_in, dl_in, f3,
                         one(anchor_state.y3))
             state.y3 = y3[0]
-            stats["stage3"] = self._stage_stats(it3, gap3)
-        f = self._flat_f(np.asarray(f3))[0]
-        return ({"f": f, "u_star": float(np.asarray(u)[0]), "r_star": r_star,
+            stats["stage3"] = self._stage_stats(*_fetch(3, it3, gap3))
+        last = 3 if not skip_stage3 else 2 if run2 else 1  # f3's stage
+        f3, u = _fetch(last, f3, u)
+        f = self._flat_f(f3)[0]
+        return ({"f": f, "u_star": float(u[0]), "r_star": r_star,
                  "stats": stats}, state)
 
     # ---- fleet batch: many fabrics (padded to this solver's V) at once ------

@@ -29,6 +29,8 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
+
 __all__ = ["IntervalMetrics", "route_metrics", "route_metrics_batched",
            "route_metrics_fleet", "p999", "summarize"]
 
@@ -117,8 +119,9 @@ def route_metrics(
     if backend == "pallas":
         from repro.kernels.linkload import ops as llops
 
-        mlu, alu, olr, load_tot = llops.link_metrics(
-            demand, weights, cap, overload_threshold)
+        with obs.span("score.linkload"):
+            mlu, alu, olr, load_tot = llops.link_metrics(
+                demand, weights, cap, overload_threshold)
         mlu, alu, olr, load_tot = (np.asarray(x) for x in (mlu, alu, olr, load_tot))
     elif backend == "jax":
         import jax.numpy as jnp

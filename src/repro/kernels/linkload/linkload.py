@@ -96,6 +96,7 @@ def linkload_pallas(demand, w, inv_cap, threshold,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bt, be), jnp.float32)],
         interpret=interpret,
+        name="linkload",
     )(demand, w, inv_cap, threshold)
     return mlu[:, 0], alu[:, 0], olr[:, 0], tot[:, 0]
 
@@ -167,6 +168,7 @@ def linkload_pallas_batched(demand, w, inv_cap, threshold,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bt, be), jnp.float32)],
         interpret=interpret,
+        name="linkload_batched",
     )(demand, w, inv_cap, threshold)
     return mlu[..., 0], alu[..., 0], olr[..., 0], tot[..., 0]
 
@@ -241,5 +243,6 @@ def linkload_pallas_fleet(demand, w, inv_cap, threshold,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bt, be), jnp.float32)],
         interpret=interpret,
+        name="linkload_fleet",
     )(demand, w, inv_cap, threshold)
     return mlu[..., 0], alu[..., 0], olr[..., 0], tot[..., 0]

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.autotune.table import (pad_to as _pad_to,
                                           resolve_tiles,
                                           shrink_bt as _shrink_bt)
@@ -61,7 +62,9 @@ def link_metrics(demand, weights, capacities, threshold: float = 0.8,
             jnp.asarray(d), jnp.asarray(w), jnp.asarray(ic),
             jnp.full((1, 1), threshold, jnp.float32),
             bt=bt, be=be, bc=bc, interpret=interpret)
-        mlu, alu_sum, olr_cnt, tot = (np.asarray(x)[:t_orig] for x in (mlu, alu_sum, olr_cnt, tot))
+        with obs.span("score.wait"):
+            out = [np.asarray(x) for x in (mlu, alu_sum, olr_cnt, tot)]
+        mlu, alu_sum, olr_cnt, tot = (x[:t_orig] for x in out)
     elif backend == "jnp":
         mlu, alu_sum, olr_cnt, tot = (
             np.asarray(x) for x in linkload_metrics_ref(

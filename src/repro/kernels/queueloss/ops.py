@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.autotune.table import (pad_to as _pad_to,
                                           resolve_tiles,
                                           shrink_bt as _shrink_bt)
@@ -68,7 +69,9 @@ def queue_loss(demand, weights, capacities, buffers, dt: float,
             jnp.asarray(d), jnp.asarray(w), jnp.asarray(cp), jnp.asarray(bf),
             jnp.full((1, 1), dt, jnp.float32),
             bt=bt, be=be, bc=bc, interpret=interpret)
-        drop, tot = (np.asarray(x, np.float64)[:ts_orig] for x in (drop, tot))
+        with obs.span("score.wait"):
+            out = [np.asarray(x) for x in (drop, tot)]
+        drop, tot = (x.astype(np.float64)[:ts_orig] for x in out)
     else:  # jnp / jax
         drop, tot = (np.asarray(x, np.float64) for x in queueloss_ref(
             jnp.asarray(demand), jnp.asarray(weights),

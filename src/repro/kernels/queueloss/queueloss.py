@@ -124,6 +124,7 @@ def queueloss_pallas(demand, w, cap, buf, dt,
             pltpu.VMEM((1, e), jnp.float32),  # per-link queue state (all E)
         ],
         interpret=interpret,
+        name="queueloss",
     )(demand, w, cap, buf, dt)
     return drop[:, 0], tot[:, 0]
 
@@ -214,6 +215,7 @@ def queueloss_pallas_batched(demand, w, cap, buf, dt,
             pltpu.VMEM((1, e), jnp.float32),  # queue state, reset per epoch
         ],
         interpret=interpret,
+        name="queueloss_batched",
     )(demand, w, cap, buf, dt)
     return drop[..., 0], tot[..., 0]
 
@@ -303,5 +305,6 @@ def queueloss_pallas_fleet(demand, w, cap, buf, dt,
             pltpu.VMEM((1, e), jnp.float32),  # queue state, reset per block
         ],
         interpret=interpret,
+        name="queueloss_fleet",
     )(demand, w, cap, buf, dt)
     return drop[..., 0], tot[..., 0]

@@ -21,6 +21,15 @@ scattered across the engines.  This is the single replacement:
 * :func:`event` / :func:`counter` — instant events and counter samples for
   controller decisions (topology updates, skips, strategy choices).
 
+Enabled, every span and ``timed`` section also enters a
+``jax.profiler.TraceAnnotation`` of its name, so under a running
+``jax.profiler`` trace the program's spans sit on the profiler's host plane,
+on one clock with the device's ops.  And each compile JAX reports lands as a
+``jax.compile`` complete event that ends when JAX reports it and lasts the
+reported seconds (arg ``cached``: it was a persistent compile-cache read),
+under whichever span caused it.  jax is imported only by
+:func:`enable`, so this module imports without it.
+
 The buffer exports as JSONL (:func:`export_jsonl`, one event per line — the
 ``repro.obs.report`` CLI input) and as Chrome ``trace_event`` JSON
 (:func:`export_chrome_trace`, loadable in ``chrome://tracing`` / Perfetto).
@@ -50,6 +59,11 @@ _enabled = False
 _events: deque = deque(maxlen=_DEFAULT_CAPACITY)  # ring buffer of tuples
 _dropped = 0  # events evicted from the full ring buffer since last clear
 _tls = threading.local()  # per-thread span nesting depth
+_annotation = None  # jax.profiler.TraceAnnotation once enable() found jax
+# JAX times each compile (persistent-cache reads included) under the first
+# name, and reports a cache read under the second just before that
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def enable(capacity: int | None = None) -> None:
@@ -58,7 +72,35 @@ def enable(capacity: int | None = None) -> None:
     if capacity is not None and capacity != _events.maxlen:
         _events = deque(maxlen=capacity)
         _dropped = 0
+    _hook_jax()
     _enabled = True
+
+
+def _hook_jax() -> None:
+    """Once per process: the profiler annotation and the compile listener."""
+    global _annotation
+    if _annotation is not None:
+        return
+    try:
+        import jax
+    except ImportError:
+        return
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    _annotation = jax.profiler.TraceAnnotation
+
+
+def _on_compile(event: str, secs: float, **_) -> None:
+    if event == _CACHE_READ:
+        _tls.cached = True
+        return
+    if event != _COMPILE:
+        return
+    cached, _tls.cached = getattr(_tls, "cached", False), False
+    if not _enabled:
+        return
+    dur = int(secs * 1e9)
+    _append(("X", "jax.compile", time.perf_counter_ns() - dur, dur,
+             threading.get_ident(), _depth(), {"cached": cached}))
 
 
 def disable() -> None:
@@ -114,12 +156,15 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0", "depth")
+    __slots__ = ("name", "args", "t0", "depth", "ann")
 
     def __init__(self, name: str, args):
         self.name = name
@@ -129,15 +174,31 @@ class _Span:
         d = _depth()
         _tls.depth = d + 1
         self.depth = d
+        self.ann = _annotate(self.name)
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         _tls.depth = self.depth
         _append(("X", self.name, self.t0, dur,
                  threading.get_ident(), self.depth, self.args))
         return False
+
+    def set(self, **attrs) -> None:
+        """Add args known only inside the span (e.g. a solver's own time)."""
+        self.args = {**(self.args or {}), **attrs}
+
+
+def _annotate(name: str):
+    """The entered profiler annotation of a recorded span, or None."""
+    if _annotation is None:
+        return None
+    ann = _annotation(name)
+    ann.__enter__()
+    return ann
 
 
 def span(name: str, **attrs):
@@ -152,7 +213,7 @@ class _Timed:
     event is recorded only when tracing was enabled at entry."""
 
     __slots__ = ("name", "args", "t0", "seconds", "depth", "_rec", "_acc",
-                 "_key")
+                 "_key", "_ann")
 
     def __init__(self, name: str, args, acc=None, key=None):
         self.name = name
@@ -167,6 +228,7 @@ class _Timed:
             d = _depth()
             _tls.depth = d + 1
             self.depth = d
+            self._ann = _annotate(self.name)
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -174,6 +236,8 @@ class _Timed:
         dur = time.perf_counter_ns() - self.t0
         self.seconds = dur * 1e-9
         if self._rec:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
             _tls.depth = self.depth
             _append(("X", self.name, self.t0, dur,
                      threading.get_ident(), self.depth, self.args))

@@ -16,8 +16,9 @@ that
 4. emits routing/topology decisions through the existing
    :func:`repro.transition.should_reconfigure` gate, and
 5. measures a decision-latency SLO: per-epoch *time-to-new-weights* (TM
-   arrival → installed weight matrix), exported through :mod:`repro.obs`
-   (``serve.*`` spans + histograms) and gated in CI
+   arrival, through scoring the finished epoch, → installed weight
+   matrix), exported through :mod:`repro.obs` (the ``serve.epoch`` span
+   tree + histograms) and gated in CI
    (``benchmarks/bench_serve.py`` + the ``latency_slo`` regression-spec
    kind).
 

@@ -17,9 +17,10 @@ stream:
   **warm-started from the previous epoch's primal/dual iterates**
   (:meth:`repro.core.jaxlp.JaxRoutingSolver.solve_routing_warm`) instead of
   the batch engine's cold middle-epoch anchor;
-* per-epoch *time-to-new-weights* is measured (TM arrival →
-  installed weight matrix) and exported through :mod:`repro.obs` as
-  ``serve.*`` spans plus a ``serve.time_to_new_weights_s`` histogram.
+* per-epoch *time-to-new-weights* is measured (TM arrival, through
+  scoring the finished epoch, → installed weight matrix) as the
+  ``serve.epoch`` span, whose children name each step, plus a
+  ``serve.time_to_new_weights_s`` histogram in :mod:`repro.obs`.
 
 Replay parity is the correctness contract (test-enforced): run over a
 recorded trace, the streaming walk makes the same epoch boundaries, the same
@@ -174,12 +175,11 @@ class StreamingController:
         opened a routing epoch (None otherwise — warm-up or mid-epoch)."""
         t = self._t
         decision = None
-        with obs.span("serve.interval", t=t):
-            if t >= self.agg and (t - self.agg) % self.route_step == 0:
-                decision = self._replan(start=t)
-            if t >= self.agg:
-                self._block.append(np.asarray(row, np.float64))
-            self.window.push(row)
+        if t >= self.agg and (t - self.agg) % self.route_step == 0:
+            decision = self._replan(start=t)
+        if t >= self.agg:
+            self._block.append(np.asarray(row, np.float64))
+        self.window.push(row)
         self._t = t + 1
         if decision is not None:
             self._decisions.append(decision)
@@ -198,34 +198,35 @@ class StreamingController:
     # ---- re-plan (the decision hot path) -------------------------------------
 
     def _replan(self, start: int) -> Decision:
-        self._score_block()  # close the finished epoch before re-planning
-        t_arrival = time.perf_counter()
-        with obs.span("serve.replan", start=start, epoch=self._epoch):
+        # the epoch's TM has arrived: time-to-new-weights runs from here,
+        # through scoring the finished epoch, to the installed weights
+        with obs.timed("serve.epoch", epoch=self._epoch, start=start) as ep:
+            self._score_block()  # close the finished epoch before re-planning
             with self._phases("plan", "serve.plan"):
                 window = self.window.view()
                 if self.strategy is None:  # warm-up ended: pick the strategy
                     self._pick_strategy(window)
-                tms = clustering.critical_tms(window, k=self.cc.k_critical,
-                                              seed=self._epoch)
+                with obs.span("serve.plan.critical_tms"):
+                    tms = clustering.critical_tms(
+                        window, k=self.cc.k_critical, seed=self._epoch)
                 self._tms_prev = tms  # quality scoring pairs tms with block
                 delta = 0.0
                 if self.strategy.hedging:
-                    delta = (self.sc.delta if self.sc.delta is not None
-                             else estimate_delta(window,
-                                                 self.sc.delta_quantile))
+                    with obs.span("serve.plan.delta"):
+                        delta = (self.sc.delta if self.sc.delta is not None
+                                 else estimate_delta(window,
+                                                     self.sc.delta_quantile))
                 topo_solved, topo_applied = self._maybe_topology(
                     start, window, tms, delta)
             with self._phases("solve", "serve.solve"):
                 u_star = self._solve_routing(tms, delta)
-        latency = time.perf_counter() - t_arrival
+        latency = ep.seconds
         self._latencies.append(latency)
         obs.metrics.observe("serve.time_to_new_weights_s", latency,
                             fabric=self.fabric.name)
         obs.metrics.inc("serve.decisions", fabric=self.fabric.name,
                         topology="applied" if topo_applied else
                         ("solved" if topo_solved else "routing_only"))
-        obs.event("serve.decision", start=start, epoch=self._epoch,
-                  latency_s=latency, topology_applied=topo_applied)
         decision = Decision(epoch=self._epoch, start=start,
                             topology_solved=topo_solved,
                             topology_applied=topo_applied,
@@ -250,38 +251,43 @@ class StreamingController:
         self._staged = None
         if self.strategy.nonuniform and (self._first_epoch
                                          or start >= self._next_topo):
-            sol = solve(self.fabric, tms, self.strategy, sc,
-                        window_demand=window)
-            self._solver_s += sol.solve_seconds
-            cand = (realize(self.fabric, sol.n_e)[0]
-                    if cc.realize_topology else sol.n_e)
-            apply = True
-            if tc is not None and self._n_realized is not None:
-                from repro.core.controller import _transition_gate
+            with obs.span("serve.plan.topology") as sp:
+                sol = solve(self.fabric, tms, self.strategy, sc,
+                            window_demand=window)
+                sp.set(highs_s=sol.solve_seconds)
+                self._solver_s += sol.solve_seconds
+                cand = (realize(self.fabric, sol.n_e)[0]
+                        if cc.realize_topology else sol.n_e)
+                apply = True
+                if tc is not None and self._n_realized is not None:
+                    from repro.core.controller import _transition_gate
 
-                apply, staged, ev, ev_s = _transition_gate(
-                    self.fabric, tms, self._n_realized, cand, tc, cc, sc,
-                    delta=delta, hedging=self.strategy.hedging,
-                    horizon_intervals=self.topo_step)
-                self._solver_s += ev_s
-                self._phases.add("transition", ev_s)
-                self._staged = staged
-                if ev is not None:
-                    self._transition_log.append(ev.log_entry(start, apply))
-            if apply:
-                self._n_realized = cand
-                self._cap = self.fabric.capacities(cand)
-                self._n_topology += 1
-                obs.event("controller.topology_applied", start=start,
-                          fabric=self.fabric.name)
-                obs.metrics.inc("controller.topology_updates",
-                                fabric=self.fabric.name, outcome="applied")
-            else:
-                self._n_skipped += 1
-                obs.event("controller.topology_skipped", start=start,
-                          fabric=self.fabric.name)
-                obs.metrics.inc("controller.topology_updates",
-                                fabric=self.fabric.name, outcome="skipped")
+                    apply, staged, ev, ev_s = _transition_gate(
+                        self.fabric, tms, self._n_realized, cand, tc, cc, sc,
+                        delta=delta, hedging=self.strategy.hedging,
+                        horizon_intervals=self.topo_step)
+                    self._solver_s += ev_s
+                    self._phases.add("transition", ev_s)
+                    self._staged = staged
+                    if ev is not None:
+                        self._transition_log.append(
+                            ev.log_entry(start, apply))
+                if apply:
+                    self._n_realized = cand
+                    self._cap = self.fabric.capacities(cand)
+                    self._n_topology += 1
+                    obs.event("controller.topology_applied", start=start,
+                              fabric=self.fabric.name)
+                    obs.metrics.inc("controller.topology_updates",
+                                    fabric=self.fabric.name,
+                                    outcome="applied")
+                else:
+                    self._n_skipped += 1
+                    obs.event("controller.topology_skipped", start=start,
+                              fabric=self.fabric.name)
+                    obs.metrics.inc("controller.topology_updates",
+                                    fabric=self.fabric.name,
+                                    outcome="skipped")
             self._next_topo = start + self.topo_step
             self._first_epoch = False
             return True, apply
@@ -299,20 +305,22 @@ class StreamingController:
         cc, sc = self.cc, self.sc
         hedging = self.strategy.hedging
         if cc.solver_backend == "pdhg":
-            solver = routing_solver_for(self.fabric, cc.k_critical,
-                                        cc.pdhg_max_iters, cc.pdhg_tol,
-                                        cc.solver_precision)
+            with obs.span("serve.solve.prepare"):
+                solver = routing_solver_for(self.fabric, cc.k_critical,
+                                            cc.pdhg_max_iters, cc.pdhg_tol,
+                                            cc.solver_precision)
+                tms_pad = _pad_tms(np.asarray(tms, float), cc.k_critical)
+                cap = np.asarray(self._cap, float)
             out, state = solver.solve_routing_warm(
-                _pad_tms(np.asarray(tms, float), cc.k_critical),
-                np.asarray(self._cap, float), hedging=hedging, delta=delta,
+                tms_pad, cap, hedging=hedging, delta=delta,
                 skip_stage3=sc.skip_stage3,
                 anchor_state=self._warm_state if self.serve.warm_start
                 else None)
             self._warm_state = state
-            f_b, u_b, n_fb = pdhg_finite_fallback(
-                self.fabric, [tms], np.asarray(self._cap, float)[None],
-                np.asarray([delta]), sc, out["f"][None],
-                np.asarray([out["u_star"]]))
+            with obs.span("serve.solve.fallback"):
+                f_b, u_b, n_fb = pdhg_finite_fallback(
+                    self.fabric, [tms], cap[None], np.asarray([delta]), sc,
+                    out["f"][None], np.asarray([out["u_star"]]))
             f, u_star = f_b[0], float(u_b[0])
             self._n_fallbacks += n_fb
             if n_fb:  # the carried iterates diverged — don't reuse them
@@ -324,7 +332,8 @@ class StreamingController:
         else:
             raise ValueError(f"unknown solver_backend {cc.solver_backend!r}")
         self._f_epochs.append(f)
-        self._w = routing_weight_matrix(self.paths, f)
+        with obs.span("serve.solve.weights"):
+            self._w = routing_weight_matrix(self.paths, f)
         return u_star
 
     # ---- scoring -------------------------------------------------------------
@@ -336,11 +345,11 @@ class StreamingController:
         if not self._block:
             return
         cc = self.cc
-        block = np.stack(self._block)
         start = self._block_start
-        self._block = []
         interval_s = self.stream.interval_minutes * 60.0
         with self._phases("score", "serve.score"):
+            block = np.stack(self._block)
+            self._block = []
             if self._tms_prev is not None:
                 obs.quality.record_epoch_quality(self.fabric.name,
                                                  self._tms_prev, block)

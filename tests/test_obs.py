@@ -57,15 +57,31 @@ def _run(fabric, trace, **over):
                           dataclasses.replace(CC, **over), SC)
 
 
+def _serve(fabric, trace, engine, solver_backend):
+    """The served path: warm PDHG, Pallas scoring with burst loss."""
+    from repro.burst import LossConfig
+    from repro.serve import ServeConfig, StreamingController, TMStream
+
+    cc = dataclasses.replace(CC, solver_backend=solver_backend,
+                             backend="pallas", loss=LossConfig(seed=1))
+    return StreamingController(
+        fabric, TMStream.from_trace(trace),
+        Strategy(nonuniform=False, hedging=True), cc, SC,
+        serve=ServeConfig(auto_strategy=False)).run().result
+
+
 # ---- tracing on/off parity (bit-identical results) --------------------------
 
 @pytest.mark.parametrize("engine,backend", [("sequential", "scipy"),
-                                            ("batched", "pdhg")])
+                                            ("batched", "pdhg"),
+                                            ("serve", "pdhg")])
 def test_tracing_parity_bit_identical(tiny_fabric, tiny_trace, engine,
                                       backend):
-    off = _run(tiny_fabric, tiny_trace, engine=engine, solver_backend=backend)
+    run = _serve if engine == "serve" else _run
+    off = run(tiny_fabric, tiny_trace, engine=engine, solver_backend=backend)
+    assert obs.events() == [], "a run with tracing off must record nothing"
     obs.enable()
-    on = _run(tiny_fabric, tiny_trace, engine=engine, solver_backend=backend)
+    on = run(tiny_fabric, tiny_trace, engine=engine, solver_backend=backend)
     assert obs.events(), "enabled run must have recorded spans"
     obs.disable()
     for k in P999:
@@ -74,6 +90,8 @@ def test_tracing_parity_bit_identical(tiny_fabric, tiny_trace, engine,
     np.testing.assert_array_equal(on.metrics.alu, off.metrics.alu)
     np.testing.assert_array_equal(on.metrics.olr, off.metrics.olr)
     np.testing.assert_array_equal(on.metrics.stretch, off.metrics.stretch)
+    if off.metrics.loss is not None:
+        np.testing.assert_array_equal(on.metrics.loss, off.metrics.loss)
     assert on.n_routing_updates == off.n_routing_updates
     assert on.n_topology_updates == off.n_topology_updates
     # phase accounting exists in both modes with the same keys
@@ -137,11 +155,78 @@ def test_disabled_overhead_under_two_percent(tiny_fabric, tiny_trace):
 def test_disabled_span_is_singleton_noop():
     assert obs.span("a") is obs.span("b", k=1)  # no allocation when disabled
     with obs.span("a"):
-        with obs.span("b"):
-            pass
+        with obs.span("b") as sp:
+            sp.set(late=1)
     obs.event("decision", x=1)
     obs.counter("c", 2.0)
     assert obs.events() == []
+
+
+# ---- one clock with the profiler, compiles as spans --------------------------
+
+def _trace_reduce():
+    """``chipbench/trace_reduce.py``, the benchmark's own reduction."""
+    import importlib.util
+    import pathlib
+
+    path = (pathlib.Path(__file__).resolve().parents[1] / "chipbench"
+            / "trace_reduce.py")
+    spec = importlib.util.spec_from_file_location("trace_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_spans_land_on_the_profiler_clock(tmp_path):
+    import jax
+
+    tr = _trace_reduce()
+    obs.enable()
+    obs.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        anchor = time.perf_counter_ns()
+        with jax.profiler.TraceAnnotation(tr.ANCHOR):
+            pass
+        with obs.span("probe.span"):
+            time.sleep(0.002)
+        phases = obs.PhaseTimes()
+        with phases("solve", "probe.timed"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    recs = {e["name"]: e for e in obs.events()}
+    pd = tr.load(tmp_path)
+    host = {n: (a, b) for n, a, b in tr.host_events(pd, prefix="probe.")}
+    assert set(host) == {"probe.span", "probe.timed"}
+    off = tr.clock_offset(pd, anchor)
+    for name, (a, b) in host.items():
+        r = recs[name]
+        assert abs(r["ts_us"] * 1e3 + off - a) < 200e3, name
+        assert abs((r["ts_us"] + r["dur_us"]) * 1e3 + off - b) < 200e3, name
+
+
+def test_compile_is_a_span_only_when_tracing():
+    import jax
+
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    obs.enable()  # hooks the compile listener ...
+    obs.disable()  # ... which then records nothing
+    f(np.ones(7, np.float32))
+    assert obs.events() == []
+    obs.enable()
+    with obs.span("outer"):
+        f(np.ones(9, np.float32))  # a new shape: one compile
+        f(np.ones(9, np.float32))  # cached in memory: none
+    comp = [e for e in obs.events() if e["name"] == "jax.compile"]
+    assert len(comp) == 1
+    (c,) = comp
+    assert c["ph"] == "X" and c["dur_us"] > 0 and "cached" in c["args"]
+    outer = next(e for e in obs.events() if e["name"] == "outer")
+    assert outer["ts_us"] <= c["ts_us"]
+    assert c["ts_us"] + c["dur_us"] <= outer["ts_us"] + outer["dur_us"]
 
 
 # ---- export round-trips ------------------------------------------------------

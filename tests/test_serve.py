@@ -206,6 +206,109 @@ def test_serve_latency_and_metrics(small_fabric, small_trace):
     assert gauges and gauges[0]["value"] == 0.0  # 10s SLO never burned
 
 
+# ---- the served epoch as a span tree -----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def traced_serve(small_fabric, small_trace):
+    """A nonuniform+hedging run on PDHG with Pallas scoring and burst loss,
+    with tracing on: its decisions and its trace events."""
+    from repro.burst import LossConfig
+
+    cc = dataclasses.replace(CC, solver_backend="pdhg", backend="pallas",
+                             loss=LossConfig(seed=3))
+    obs.enable()
+    obs.clear()
+    try:
+        res = _stream_run(small_fabric, small_trace,
+                          Strategy(nonuniform=True, hedging=True), cc)
+        spans = obs.events()
+    finally:
+        obs.disable()
+        obs.clear()
+    return res, spans
+
+
+def _held(outer, spans, name):
+    """The spans called ``name`` that ``outer`` holds (same thread, inside
+    its interval)."""
+    end = outer["ts_us"] + outer["dur_us"] + 1e-3
+    return [e for e in spans if e["name"] == name
+            and e["tid"] == outer["tid"] and e is not outer
+            and e["ts_us"] >= outer["ts_us"] - 1e-3
+            and e["ts_us"] + e["dur_us"] <= end]
+
+
+def _named(spans, name):
+    return [e for e in spans if e["name"] == name]
+
+
+def test_serve_epoch_holds_the_layer_spans(traced_serve):
+    res, spans = traced_serve
+    epochs = _named(spans, "serve.epoch")
+    assert len(epochs) == len(res.decisions) > 2
+    assert [e["args"]["epoch"] for e in epochs] == [
+        d.epoch for d in res.decisions]
+    assert [e["args"]["start"] for e in epochs] == [
+        d.start for d in res.decisions]
+    for i, ep in enumerate(epochs):
+        # the first epoch has no finished block to score
+        layers = ("serve.plan", "serve.solve") + (("serve.score",) if i
+                                                  else ())
+        held = {n: _held(ep, spans, n) for n in layers}
+        assert all(len(v) == 1 for v in held.values()), (i, held)
+        covered = sum(v[0]["dur_us"] for v in held.values())
+        assert covered >= 0.95 * ep["dur_us"], (i, covered, ep["dur_us"])
+    names = {e["name"] for e in spans}
+    for gone in ("serve.interval", "serve.replan", "serve.decision"):
+        assert gone not in names
+
+
+def test_serve_topology_epochs_hold_the_topology_span(traced_serve):
+    res, spans = traced_serve
+    epochs = _named(spans, "serve.epoch")
+    topo = [d.topology_solved for d in res.decisions]
+    assert any(topo) and not all(topo)
+    for ep, solved in zip(epochs, topo):
+        (plan,) = _held(ep, spans, "serve.plan")
+        held = _held(plan, spans, "serve.plan.topology")
+        assert len(held) == int(solved)
+        assert len(_held(plan, spans, "serve.plan.critical_tms")) == 1
+        assert len(_held(plan, spans, "serve.plan.delta")) == 1
+        if solved:
+            assert held[0]["args"]["highs_s"] > 0
+
+
+def test_serve_solve_and_score_mark_their_waits(traced_serve):
+    _, spans = traced_serve
+    for solve in _named(spans, "serve.solve"):
+        waits = _held(solve, spans, "jaxlp.wait")
+        assert waits and {w["args"]["stage"] for w in waits} == {1, 2, 3}
+        for name in ("serve.solve.prepare", "jaxlp.warm_inputs",
+                     "jaxlp.warm_stage1", "serve.solve.fallback",
+                     "serve.solve.weights"):
+            assert len(_held(solve, spans, name)) == 1, name
+    for score in _named(spans, "serve.score"):
+        for name in ("score.linkload", "score.bursts", "score.queueloss"):
+            assert len(_held(score, spans, name)) == 1, name
+        assert len(_held(score, spans, "score.wait")) == 2
+    # a wait holds a copy and nothing else: no span inside it
+    for w in (e for e in spans if e["name"].endswith(".wait")):
+        assert not [e for e in spans if e is not w and e["tid"] == w["tid"]
+                    and e["ts_us"] >= w["ts_us"]
+                    and e["ts_us"] + e["dur_us"] <= w["ts_us"] + w["dur_us"]
+                    and e["name"] != "jax.compile"]
+
+
+def test_decision_latency_is_the_epoch_span(traced_serve):
+    res, spans = traced_serve
+    epochs = _named(spans, "serve.epoch")
+    lat = np.asarray([e["dur_us"] * 1e-6 for e in epochs])
+    np.testing.assert_allclose(res.latencies_s, lat, atol=1e-3)
+    np.testing.assert_allclose([d.latency_s for d in res.decisions], lat,
+                               atol=1e-3)
+
+
 def test_serve_rejects_offline_only_configs(small_fabric, small_trace):
     from repro.failures.config import FailureConfig
 
