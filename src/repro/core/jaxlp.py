@@ -77,14 +77,15 @@ class RoutingWarmState:
     cold start.  Stage-2/3 fields are ``None`` when the producing solve did
     not run that stage (no hedging / ``skip_stage3``); a ``None`` field falls
     back to the cold init for just that stage.  Arrays stay device-resident
-    (jax arrays) so carrying the state adds no host round-trips.
+    (jax arrays, with the served program's leading batch axis of 1) so
+    carrying the state adds no host round-trips.
     """
 
-    f1: object  # (V, V, V) stage-1 primal splits
-    y1: object  # (m, V, V) stage-1 dual
+    f1: object  # (1, V, V, V) stage-1 primal splits
+    y1: object  # (1, m, V, V) stage-1 dual
     f2: object | None = None  # stage-2 primal splits
     y2: object | None = None  # stage-2 MLU dual
-    z2: object | None = None  # stage-2 risk dual
+    z2: object | None = None  # stage-2 risk dual, (1, V, V, V, 2)
     y3: object | None = None  # stage-3 MLU dual
 
 
@@ -169,14 +170,6 @@ def _project_simplex_topk(x: jax.Array, valid: jax.Array, k: int) -> jax.Array:
     return out / jnp.maximum(out.sum(), 1e-30)
 
 
-def _fetch(stage: int, *xs) -> list:
-    """Device-to-host copies of ``xs``, the host waiting on the device for
-    PDHG stage ``stage``: a ``jaxlp.wait`` span holds the copies and nothing
-    else."""
-    with obs.span("jaxlp.wait", stage=stage):
-        return [np.asarray(x) for x in xs]
-
-
 @dataclasses.dataclass(eq=False)  # identity hash: each instance owns a jit cache
 class JaxRoutingSolver:
     """Per-(fabric, m) jitted PDHG routing solver.
@@ -259,23 +252,37 @@ class JaxRoutingSolver:
         self.mask_kj = jnp.asarray(1.0 - np.eye(v), np.float32)  # [k != j]
         # path length per slot: 1 for the direct slot (k = j), else 2
         self._len3 = jnp.asarray(np.where(kk == jj, 1.0, 2.0), jnp.float32)
+        # the served program's constant mask, and placeholders for iterates a
+        # cold stage does not read (f1, y1, f2, y2, z2, y3)
+        self._valid1 = self.valid[None]
+        f0, y0 = np.zeros((1, v, v, v), np.float32), np.zeros(
+            (1, self.m, v, v), np.float32)
+        self._served_cold = (f0, y0, f0, y0,
+                             np.zeros((1, v, v, v, 2), np.float32), y0)
 
     # ---- dense conversions ---------------------------------------------------
 
-    def _dense_tms(self, tms: np.ndarray) -> jnp.ndarray:
-        """(m, C) commodity TMs → (m, V, V) dense pod matrices."""
+    def _dense_tms_np(self, tms: np.ndarray) -> np.ndarray:
+        """(m, C) commodity TMs → (m, V, V) dense pod matrices (host)."""
         tms = np.asarray(tms, np.float32)
         out = np.zeros((tms.shape[0], self.V * self.V), np.float32)
         out[:, self._comm_flat] = tms
-        return jnp.asarray(out.reshape(tms.shape[0], self.V, self.V))
+        return out.reshape(tms.shape[0], self.V, self.V)
 
-    def _dense_inv_cap(self, capacities: np.ndarray) -> jnp.ndarray:
-        """(E,) directed capacities → (V, V) dense inverse capacities."""
+    def _dense_tms(self, tms: np.ndarray) -> jnp.ndarray:
+        return jnp.asarray(self._dense_tms_np(tms))
+
+    def _dense_inv_cap_np(self, capacities: np.ndarray) -> np.ndarray:
+        """(E,) directed capacities → (V, V) dense inverse capacities (host;
+        the reciprocal in f64, stored in f32)."""
         cap = np.asarray(capacities, np.float64)
         ic = np.where(cap > 1e-9, 1.0 / np.maximum(cap, 1e-9), 0.0)
         out = np.zeros((self.V * self.V,), np.float32)
         out[self._comm_flat] = ic
-        return jnp.asarray(out.reshape(self.V, self.V))
+        return out.reshape(self.V, self.V)
+
+    def _dense_inv_cap(self, capacities: np.ndarray) -> jnp.ndarray:
+        return jnp.asarray(self._dense_inv_cap_np(capacities))
 
     def _flat_f(self, f3: np.ndarray) -> np.ndarray:
         """(..., V, V, V) splits → (..., P) in the PathSet layout."""
@@ -772,6 +779,54 @@ class JaxRoutingSolver:
 
     # ---- single-epoch streaming solve, warm-started across epochs -----------
 
+    @functools.partial(jax.jit, static_argnums=(0, 1))
+    def _solve_served(self, skip_stage3, d3, ic, valid, delta, flags,
+                      f1, y1, f2, y2, z2, y3):
+        """Stages 1 → [2] → [3] of one served epoch as one device program.
+
+        Every array has a leading batch axis of 1, so each stage is the
+        ``*_batch[_warm]`` program's own vmapped core.  ``flags`` is
+        ``(run2, warm1, warm2, warm3)``: stage 2 runs under a ``lax.cond``
+        on ``run2``, and a stage starts from the carried iterates where its
+        ``warm`` flag is set, else from its cold init (the carried arguments
+        are then placeholders).  Returns ``(host, state)``: what the host
+        fetches, and the iterates that stay on the device.
+        """
+        run2, warm1, warm2, warm3 = flags[0], flags[1], flags[2], flags[3]
+
+        def pick(warm, carried, cold):
+            return jnp.where(warm, carried, cold)
+
+        cf, cy = jax.vmap(self._mlu_inits)(d3, ic, valid)
+        f1, u, it1, y1, gap1 = jax.vmap(self._mlu_core)(
+            d3, ic, valid, pick(warm1, f1, cf), pick(warm1, y1, cy))
+        u_budget = u * 1.005 + 1e-9
+
+        def stage2(_):
+            cf, cy, cz = jax.vmap(self._risk_inits)(d3, valid)
+            f, r, _, y, z, it, gap = jax.vmap(self._risk_core)(
+                d3, ic, valid, u_budget, delta, pick(warm2, f2, cf),
+                pick(warm2, y2, cy), pick(warm2, z2, cz))
+            return f, f, r, y, z, it, gap
+
+        def no_stage2(_):
+            return (f1, f2, jnp.zeros_like(u), y2, z2, jnp.zeros_like(it1),
+                    jnp.zeros_like(gap1))
+
+        f3, f2, r, y2, z2, it2, gap2 = jax.lax.cond(run2, stage2, no_stage2,
+                                                    None)
+        host = {"f3": f3, "u": u, "r": r, "stage1": (it1, gap1),
+                "stage2": (it2, gap2)}
+        if not skip_stage3:
+            r_in = jnp.where(run2, r * 1.005 + 1e-12, 1e9)
+            dl_in = jnp.where(run2, delta, 0.0)
+            (cy,) = jax.vmap(self._stretch_inits)(d3)
+            f3, y3, it3, gap3 = jax.vmap(self._stretch_core)(
+                d3, ic, valid, u_budget, r_in, dl_in, f3,
+                pick(warm3, y3, cy))
+            host.update(f3=f3, stage3=(it3, gap3))
+        return host, (f1, y1, f2, y2, z2, y3)
+
     def solve_routing_warm(self, tms: np.ndarray, capacities: np.ndarray,
                            hedging: bool, delta: float = 0.0,
                            skip_stage3: bool = False,
@@ -788,8 +843,9 @@ class JaxRoutingSolver:
         matches a cold solve to solver tolerance (test-enforced); only the
         iteration count drops.
 
-        Reuses the ``*_batch`` jitted programs at ``B = 1``, so a process that
-        already ran the batched engine pays no extra compiles.
+        All stages run in one device program (:meth:`_solve_served`, one
+        compile per ``skip_stage3``), dispatched once; the host fetches its
+        outputs in one copy and the iterates stay on the device.
 
         Args:
           tms: (m, C) critical TMs, zero-padded to the static ``m``.
@@ -806,62 +862,41 @@ class JaxRoutingSolver:
         telemetry in the :meth:`solve_routing_batch` schema, batch length 1);
         ``state`` seeds the next call.
         """
+        run2 = bool(hedging and delta > 0)
+        st = anchor_state
         with obs.span("jaxlp.warm_inputs"):
-            d3 = self._dense_tms(tms)[None]
-            ic = self._dense_inv_cap(capacities)[None]
-            valid_b = self._tile_valid(1)
-
-        def one(x):
-            return jnp.asarray(x)[None]
-
-        with obs.span("jaxlp.warm_stage1"):
-            if anchor_state is None:
-                f3, u, it1, y1, gap1 = self._solve_mlu_batch(d3, ic, valid_b)
-            else:
-                f3, u, it1, y1, gap1 = self._solve_mlu_batch_warm(
-                    d3, ic, valid_b, one(anchor_state.f1), one(anchor_state.y1))
-        state = RoutingWarmState(f1=f3[0], y1=y1[0])
-        u_budget = jnp.asarray(u) * 1.005 + 1e-9
-        stats = {"stage1": self._stage_stats(*_fetch(1, it1, gap1)),
-                 "anchor_seconds": 0.0}
-        r_star = None
-        run2 = hedging and delta > 0
-        if run2:
-            dl = jnp.asarray([delta], jnp.float32)
-            with obs.span("jaxlp.warm_stage2"):
-                if anchor_state is None or anchor_state.f2 is None:
-                    f3r, r, _, y2, z2, it2, gap2 = self._solve_risk_batch(
-                        d3, ic, valid_b, u_budget, dl)
-                else:
-                    f3r, r, _, y2, z2, it2, gap2 = self._solve_risk_batch_warm(
-                        d3, ic, valid_b, u_budget, dl,
-                        one(anchor_state.f2), one(anchor_state.y2),
-                        one(anchor_state.z2))
-            f3 = f3r
-            state.f2, state.y2, state.z2 = f3r[0], y2[0], z2[0]
-            (r,) = _fetch(2, r)
-            r_star = float(r[0])
-            stats["stage2"] = self._stage_stats(*_fetch(2, it2, gap2),
-                                                active=np.asarray([True]))
-        if not skip_stage3:
-            r_in = jnp.asarray([r_star * 1.005 + 1e-12 if run2 else 1e9],
-                               jnp.float32)
-            dl_in = jnp.asarray([delta if run2 else 0.0], jnp.float32)
-            f3 = jnp.asarray(f3)
-            with obs.span("jaxlp.warm_stage3"):
-                if anchor_state is None or anchor_state.y3 is None:
-                    f3, y3, it3, gap3 = self._solve_stretch_batch(
-                        d3, ic, valid_b, u_budget, r_in, dl_in, f3)
-                else:
-                    f3, y3, it3, gap3 = self._solve_stretch_batch_warm(
-                        d3, ic, valid_b, u_budget, r_in, dl_in, f3,
-                        one(anchor_state.y3))
-            state.y3 = y3[0]
-            stats["stage3"] = self._stage_stats(*_fetch(3, it3, gap3))
+            d3 = self._dense_tms_np(tms)[None]
+            ic = self._dense_inv_cap_np(capacities)[None]
+            warm = (st is not None, st is not None and st.f2 is not None,
+                    st is not None and st.y3 is not None)
+            carried = (st.f1, st.y1) if warm[0] else self._served_cold[:2]
+            carried += ((st.f2, st.y2, st.z2) if warm[1]
+                        else self._served_cold[2:5])
+            carried += (st.y3,) if warm[2] else self._served_cold[5:]
+            flags = np.asarray((run2,) + warm)
+            dl = np.asarray([delta], np.float32)
+        with obs.span("jaxlp.warm_solve"):
+            host, dev = self._solve_served(bool(skip_stage3), d3, ic,
+                                           self._valid1, dl, flags, *carried)
+        if not run2:
+            del host["stage2"]
         last = 3 if not skip_stage3 else 2 if run2 else 1  # f3's stage
-        f3, u = _fetch(last, f3, u)
-        f = self._flat_f(f3)[0]
-        return ({"f": f, "u_star": float(u[0]), "r_star": r_star,
+        with obs.span("jaxlp.wait", stage=last):
+            host = jax.device_get(host)
+        stats = {"stage1": self._stage_stats(*host["stage1"]),
+                 "anchor_seconds": 0.0}
+        state = RoutingWarmState(f1=dev[0], y1=dev[1])
+        r_star = None
+        if run2:
+            r_star = float(host["r"][0])
+            stats["stage2"] = self._stage_stats(*host["stage2"],
+                                                active=np.asarray([True]))
+            state.f2, state.y2, state.z2 = dev[2:5]
+        if not skip_stage3:
+            stats["stage3"] = self._stage_stats(*host["stage3"])
+            state.y3 = dev[5]
+        f = self._flat_f(host["f3"])[0]
+        return ({"f": f, "u_star": float(host["u"][0]), "r_star": r_star,
                  "stats": stats}, state)
 
     # ---- fleet batch: many fabrics (padded to this solver's V) at once ------

@@ -1,12 +1,12 @@
 """Ahead-of-time compiles for a described TPU v5e (no chip needed).
 
-The scoring kernels and the PDHG stage programs are compiled by the TPU's own
-compiler at the widths the controller runs: F21/F22 have 12 pods, so
-C = E = 132 commodities/links, padded to 256 by the kernel wrappers; the time
-tiles are what ``shrink_bt`` makes of a 3-interval routing epoch (8 rows) and
-of its 36 loss sub-steps (40 rows).  A compile that passes here is not a chip
-run: it proves only that Mosaic/XLA accept the tiling, the VMEM use and the
-sharding.
+The scoring kernels, the PDHG stage programs and the served solve are
+compiled by the TPU's own compiler at the widths the controller runs: F21/F22
+have 12 pods, so C = E = 132 commodities/links, padded to 256 by the kernel
+wrappers; the time tiles are what ``shrink_bt`` makes of a 3-interval
+routing epoch (8 rows) and of its 36 loss sub-steps (40 rows).  A compile
+that passes here is not a chip run: it proves only that Mosaic/XLA accept the
+tiling, the VMEM use and the sharding.
 
 The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU library, and test workers import every
@@ -105,11 +105,26 @@ def _stage_args(sh, b, warm, stage):
 @pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
 @pytest.mark.parametrize("stage", ["mlu", "risk", "stretch"])
 def test_pdhg_stage_compiles_for_v5e(one_chip, solver, stage, warm):
-    """The ``*_batch`` / ``*_batch_warm`` programs at B = 1 (the streaming
-    controller's shape); the f32 operators must carry full f32 precision."""
+    """The ``*_batch`` / ``*_batch_warm`` programs at B = 1; the f32
+    operators must carry full f32 precision."""
     name = f"_solve_{stage}_batch" + ("_warm" if warm else "")
     lowered = getattr(JaxRoutingSolver, name).lower(
         solver, *_stage_args(one_chip, 1, warm, stage))
+    assert "HIGHEST" in lowered.as_text()
+    assert lowered.compile().memory_analysis() is not None
+
+
+@pytest.mark.parametrize("skip_stage3", [False, True],
+                         ids=["stages123", "skip_stage3"])
+def test_served_solve_compiles_for_v5e(one_chip, solver, skip_stage3):
+    """The streaming controller's one program per epoch (stages 1 → [2] →
+    [3], B = 1); its f32 operators must carry full f32 precision."""
+    s = lambda *shape: _shape(one_chip, (1,) + shape)  # noqa: E731
+    args = [s(M, V, V), s(V, V), _shape(one_chip, (1, V, V, V), jnp.bool_),
+            s(), _shape(one_chip, (4,), jnp.bool_),  # delta, flags
+            s(V, V, V), s(M, V, V), s(V, V, V), s(M, V, V), s(V, V, V, 2),
+            s(M, V, V)]  # carried f1, y1, f2, y2, z2, y3
+    lowered = JaxRoutingSolver._solve_served.lower(solver, skip_stage3, *args)
     assert "HIGHEST" in lowered.as_text()
     assert lowered.compile().memory_analysis() is not None
 
