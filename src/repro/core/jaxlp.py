@@ -357,6 +357,10 @@ class JaxRoutingSolver:
     def _proj_f(self, f3, valid):
         return _michelot_rows(f3, valid, self.V)
 
+    @functools.partial(jax.jit, static_argnums=0)
+    def _proj_f_batch(self, f3, valid):
+        return jax.vmap(self._proj_f)(f3, valid)
+
     def _dual_min(self, coeff, valid):
         """Σ over commodities of ``min_k coeff[i, j, k]`` (valid slots only) —
         the exact minimum of a linear functional over the product of
@@ -677,7 +681,8 @@ class JaxRoutingSolver:
 
     def solve_routing_batch(self, tms: np.ndarray, capacities: np.ndarray,
                             hedging: bool, deltas: np.ndarray | None = None,
-                            skip_stage3: bool = False):
+                            skip_stage3: bool = False,
+                            anchor: int | None = None):
         """Stages 1 → [2] → 3 for a batch of routing epochs in three vmapped
         jit calls, warm-started from a single **anchor** solve.
 
@@ -692,41 +697,63 @@ class JaxRoutingSolver:
           hedging: run stage 2 (elements with ``deltas == 0`` keep stage 1's f).
           deltas: (B,) burst sizes (ignored unless ``hedging``).
           skip_stage3: skip the stretch-minimization stage.
+          anchor: the element solved cold (default ``B // 2``); a caller that
+            pads its batch to a fixed size names its real middle element.
+
+        Where some element has a dead (zero-capacity) link, every path over
+        it is left out of that element's slot mask (:meth:`valid_for_caps`;
+        the operators alone give a dead link ``inv_cap = 0``, free capacity)
+        and the last stage's splits are projected onto each commodity's
+        simplex of the paths left (a stage's last iterate is a Halpern
+        extrapolation and can leave a path a small negative split).  With
+        every link live the masks and splits are the solver's own.  The
+        batch's outputs come back in one fetch, the ``jaxlp.batch_wait``
+        span.
 
         Returns dict with ``f`` (B, P), ``u_star`` (B,), ``r_star`` (B,) or
         None, and ``stats`` — per-epoch PDHG telemetry per stage (iteration
         counts, final certified relative gaps, Halpern restart counts; stage 2
-        carries an ``active`` mask for the elements that actually hedge).
+        carries an ``active`` mask for the elements that actually hedge) and
+        the anchor solves' iterations (``anchor_iters``, per stage).
         The telemetry is always part of the jitted programs' outputs, so
         enabling/disabling tracing cannot retrace or perturb the solve.
         """
         b = tms.shape[0]
-        d3 = jnp.stack([self._dense_tms(t) for t in tms])
-        ic = jnp.stack([self._dense_inv_cap(c) for c in capacities])
-        a = b // 2  # anchor epoch
-        valid_b = self._tile_valid(b)
+        d3_np = np.stack([self._dense_tms_np(t) for t in tms])
+        ic_np = np.stack([self._dense_inv_cap_np(c) for c in capacities])
+        a = b // 2 if anchor is None else int(anchor)
+        live = self.valid_for_caps(ic_np)
+        if live is None:
+            valid_a, valid_b = self.valid, self._tile_valid(b)
+        else:
+            valid_a, valid_b = jnp.asarray(live[a]), jnp.asarray(live)
+        d3, ic = jnp.asarray(d3_np), jnp.asarray(ic_np)
+        d3_a, ic_a = jnp.asarray(d3_np[a]), jnp.asarray(ic_np[a])
         anchor_s = 0.0
+        anchor_it = {}
 
         def tile(x):
             return jnp.broadcast_to(x[None], (b,) + x.shape)
 
         with obs.timed("jaxlp.anchor", stage="mlu") as t:
-            f_a, _, _, y_a, _ = jax.block_until_ready(
-                self._solve_mlu(d3[a], ic[a], self.valid))
+            f_a, _, anchor_it["stage1"], y_a, _ = jax.block_until_ready(
+                self._solve_mlu(d3_a, ic_a, valid_a))
         anchor_s += t.seconds
         with obs.span("jaxlp.stage1", b=b):
             f3, u, it1, _, gap1 = self._solve_mlu_batch_warm(
                 d3, ic, valid_b, tile(f_a), tile(y_a))
         u = jnp.asarray(u)
         u_budget = u * 1.005 + 1e-9
-        stats = {"stage1": self._stage_stats(it1, gap1)}
+        dev = {"stage1": (it1, gap1)}
         r_star = None
+        active = None
         if hedging:
-            dl = jnp.asarray(np.asarray(deltas, np.float32))
+            dl_np = np.asarray(deltas, np.float32)
+            dl = jnp.asarray(dl_np)
             with obs.timed("jaxlp.anchor", stage="risk") as t:
-                f2_a, _, _, y2_a, z2_a, _, _ = jax.block_until_ready(
-                    self._solve_risk(d3[a], ic[a], self.valid,
-                                     u_budget[a], dl[a]))
+                f2_a, _, _, y2_a, z2_a, anchor_it["stage2"], _ = \
+                    jax.block_until_ready(self._solve_risk(
+                        d3_a, ic_a, valid_a, u_budget[a], dl[a]))
             anchor_s += t.seconds
             with obs.span("jaxlp.stage2", b=b):
                 f3r, r, _, _, _, it2, gap2 = self._solve_risk_batch_warm(
@@ -735,8 +762,8 @@ class JaxRoutingSolver:
             use = (dl > 0)[:, None, None, None]
             f3 = jnp.where(use, f3r, f3)
             r_star = jnp.where(dl > 0, jnp.asarray(r), np.inf)
-            stats["stage2"] = self._stage_stats(it2, gap2,
-                                                active=np.asarray(dl > 0))
+            dev["stage2"] = (it2, gap2)
+            active = dl_np > 0
         if not skip_stage3:
             if r_star is None:
                 r_in = jnp.full((b,), 1e9, jnp.float32)
@@ -748,22 +775,31 @@ class JaxRoutingSolver:
                                   jnp.asarray(np.asarray(deltas, np.float32)), 0.0)
             f3 = jnp.asarray(f3)
             with obs.timed("jaxlp.anchor", stage="stretch") as t:
-                _, y3_a, _, _ = jax.block_until_ready(self._solve_stretch(
-                    d3[a], ic[a], self.valid, u_budget[a], r_in[a],
-                    dl_in[a], f3[a]))
+                _, y3_a, anchor_it["stage3"], _ = jax.block_until_ready(
+                    self._solve_stretch(d3_a, ic_a, valid_a, u_budget[a],
+                                        r_in[a], dl_in[a], f3[a]))
             anchor_s += t.seconds
             with obs.span("jaxlp.stage3", b=b):
                 f3, _, it3, gap3 = self._solve_stretch_batch_warm(
                     d3, ic, valid_b, u_budget, r_in, dl_in, f3, tile(y3_a))
-            stats["stage3"] = self._stage_stats(it3, gap3)
-        f = self._flat_f(np.asarray(f3))
+            dev["stage3"] = (it3, gap3)
+        if live is not None:
+            f3 = self._proj_f_batch(f3, valid_b)
+        with obs.span("jaxlp.batch_wait"):
+            host = jax.device_get({"f3": f3, "u": u, "r": r_star, "dev": dev,
+                                   "anchor": anchor_it})
+        stats = {k: self._stage_stats(*v, active=active if k == "stage2"
+                                      else None)
+                 for k, v in host["dev"].items()}
+        f = self._flat_f(host["f3"])
         out_r = None
         if r_star is not None:
-            rr = np.asarray(r_star, np.float64)
+            rr = np.asarray(host["r"], np.float64)
             out_r = np.where(np.isfinite(rr), rr, np.nan)
         stats["anchor_seconds"] = anchor_s
-        return {"f": f, "u_star": np.asarray(u, np.float64), "r_star": out_r,
-                "stats": stats}
+        stats["anchor_iters"] = {k: int(v) for k, v in host["anchor"].items()}
+        return {"f": f, "u_star": np.asarray(host["u"], np.float64),
+                "r_star": out_r, "stats": stats}
 
     def _stage_stats(self, it, gap, active=None) -> dict:
         """Host-side per-element telemetry for one batched stage.  Restarts
@@ -911,6 +947,22 @@ class JaxRoutingSolver:
                                  indexing="ij")
         real = (ii < n_real) & (jj < n_real) & (kk < n_real)
         return np.asarray(self.valid) & real
+
+    def valid_for_caps(self, ic: np.ndarray) -> np.ndarray | None:
+        """Slot masks ``(..., V, V, V)`` without the paths that cross a dead
+        link, for dense inverse capacities ``ic (..., V, V)`` (0 = dead), or
+        None when every link is live.  A commodity with no live path keeps
+        its structural slots (the caller reports it stranded)."""
+        live = np.asarray(ic) > 0
+        if live[..., np.asarray(self.notdiag)].all():
+            return None
+        v = self.V
+        direct = np.arange(v)[None, :, None] == np.arange(v)[None, None, :]
+        hop1 = live[..., :, None, :]  # edge (i, k)
+        hop2 = np.swapaxes(live, -1, -2)[..., None, :, :]  # edge (k, j)
+        ok = np.asarray(self.valid) & hop1 & (direct | hop2)
+        return np.where(ok.any(-1, keepdims=True), ok,
+                        np.asarray(self.valid))
 
     def _fleet_fns(self, mesh):
         """Jitted batched stage solves for the fleet path, optionally

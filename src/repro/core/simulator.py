@@ -167,6 +167,7 @@ def route_metrics_batched(
     loss_cfg=None,
     loss_seeds: list | None = None,
     interval_seconds: float | None = None,
+    pad_to: int | None = None,
 ) -> IntervalMetrics:
     """Single-pass scoring of an entire controller sweep.
 
@@ -188,6 +189,9 @@ def route_metrics_batched(
         :class:`repro.burst.LossConfig` and per-epoch seeds, also computes
         the burst-level loss fraction (seeds must match the sequential
         controller's ``cfg.seed + start`` so comparisons stay paired).
+      pad_to: score at least this many elements, repeating the last one, so
+        that calls with fewer blocks run the same compiled kernels; the
+        repeats are dropped.
 
     Returns the concatenated :class:`IntervalMetrics` over all epochs, in
     epoch order — identical layout to the sequential controller's concat.
@@ -197,10 +201,18 @@ def route_metrics_batched(
     b = len(blocks)
     if b == 0:
         return IntervalMetrics.empty()
+    n_rep = max(b, pad_to or 0) - b
+    if n_rep:
+        blocks = list(blocks) + [blocks[-1]] * n_rep
+        weights = np.concatenate([weights, np.repeat(weights[-1:], n_rep, 0)])
+        capacities = np.concatenate([capacities,
+                                     np.repeat(capacities[-1:], n_rep, 0)])
+        if loss_seeds is not None:
+            loss_seeds = list(loss_seeds) + [loss_seeds[-1]] * n_rep
     lens = [np.asarray(bl).shape[0] for bl in blocks]
     t_pad = max(lens)
     c = np.asarray(blocks[0]).shape[1]
-    demand_b = np.zeros((b, t_pad, c), np.float64)
+    demand_b = np.zeros((len(blocks), t_pad, c), np.float64)
     for i, bl in enumerate(blocks):
         demand_b[i, : lens[i]] = np.asarray(bl, np.float64)
     kernel_backend = {"numpy": "numpy", "jax": "jnp", "pallas": "pallas"}[backend]
@@ -223,7 +235,7 @@ def route_metrics_batched(
         [np.asarray(arr[i][: lens[i]], np.float64) for i in range(b)])
     return IntervalMetrics(
         mlu=trim(mlu_b), alu=trim(alu_b), olr=trim(olr_b), stretch=trim(stretch_b),
-        loss=np.concatenate(loss_list) if loss_list is not None else None)
+        loss=np.concatenate(loss_list[:b]) if loss_list is not None else None)
 
 
 def route_metrics_fleet(

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from typing import TYPE_CHECKING
 
 import numpy as np
 
@@ -50,6 +51,9 @@ from repro.core.simulator import IntervalMetrics, route_metrics, summarize
 from repro.core.solver import SolverConfig, Strategy, solve
 from repro.serve.stream import TMStream
 from repro.serve.window import RollingWindow
+
+if TYPE_CHECKING:
+    from repro.transition import TransitionEval
 
 __all__ = ["ServeConfig", "Decision", "ServeResult", "StreamingController"]
 
@@ -80,6 +84,10 @@ class Decision:
     topology_applied: bool  # ... and its candidate was installed
     u_star: float  # certified stage-1 MLU bound of the routing solve
     latency_s: float  # time-to-new-weights for this epoch
+    # the §4.6 gate's evaluation of this epoch's topology change (None when
+    # no gate ran or the change needed no jumper moves); whether the change
+    # was installed is ``topology_applied``
+    gate: TransitionEval | None = None
 
 
 @dataclasses.dataclass
@@ -157,6 +165,7 @@ class StreamingController:
         self._warm_state = None  # RoutingWarmState carried epoch -> epoch
         self._f_epochs: list = []  # per-epoch splits (transit fraction)
         self._staged = None  # TransitionEval draining the current epoch
+        self._gate = None  # this epoch's gate evaluation (Decision.gate)
         self._tms_prev = None  # critical TMs of the epoch being scored
         self._block: list = []  # current epoch's scored-interval buffer
         self._block_start = 0
@@ -230,7 +239,8 @@ class StreamingController:
         decision = Decision(epoch=self._epoch, start=start,
                             topology_solved=topo_solved,
                             topology_applied=topo_applied,
-                            u_star=u_star, latency_s=latency)
+                            u_star=u_star, latency_s=latency,
+                            gate=self._gate)
         self._epoch += 1
         self._block_start = start
         return decision
@@ -248,7 +258,7 @@ class StreamingController:
     def _maybe_topology(self, start, window, tms, delta):
         """Joint topology solve + §4.6 gate; mirrors the offline plan walk."""
         cc, sc, tc = self.cc, self.sc, self.cc.transition
-        self._staged = None
+        self._staged = self._gate = None
         if self.strategy.nonuniform and (self._first_epoch
                                          or start >= self._next_topo):
             with obs.span("serve.plan.topology") as sp:
@@ -262,13 +272,14 @@ class StreamingController:
                 if tc is not None and self._n_realized is not None:
                     from repro.core.controller import _transition_gate
 
-                    apply, staged, ev, ev_s = _transition_gate(
-                        self.fabric, tms, self._n_realized, cand, tc, cc, sc,
-                        delta=delta, hedging=self.strategy.hedging,
-                        horizon_intervals=self.topo_step)
+                    with obs.span("serve.plan.transition"):
+                        apply, staged, ev, ev_s = _transition_gate(
+                            self.fabric, tms, self._n_realized, cand, tc, cc,
+                            sc, delta=delta, hedging=self.strategy.hedging,
+                            horizon_intervals=self.topo_step)
                     self._solver_s += ev_s
                     self._phases.add("transition", ev_s)
-                    self._staged = staged
+                    self._staged, self._gate = staged, ev
                     if ev is not None:
                         self._transition_log.append(
                             ev.log_entry(start, apply))
@@ -356,21 +367,8 @@ class StreamingController:
             rem_lo, rem_seed = 0, (cc.loss.seed + start
                                    if cc.loss is not None else None)
             if self._staged is not None:
-                from repro.core.simulator import route_metrics_batched
-                from repro.transition import stage_partition
-
-                ev = self._staged
-                spans, seeds, rem_lo, rem_seed = stage_partition(
-                    ev, block.shape[0], start,
-                    cc.loss.seed if cc.loss is not None else None)
-                idx = [k for k, _, _ in spans]
-                self._metrics = self._metrics.concat(route_metrics_batched(
-                    [block[lo:hi] for _, lo, hi in spans],
-                    ev.stage_w[idx], ev.stage_caps[idx],
-                    cc.overload_threshold, backend=cc.backend,
-                    loss_cfg=cc.loss, loss_seeds=seeds,
-                    interval_seconds=interval_s))
-                self._staged = None
+                rem_lo, rem_seed = self._score_staged(block, start,
+                                                      interval_s)
             if block.shape[0] - rem_lo > 0:
                 loss_cfg = (dataclasses.replace(cc.loss, seed=rem_seed)
                             if cc.loss is not None else None)
@@ -379,6 +377,30 @@ class StreamingController:
                                   loss_cfg=loss_cfg,
                                   interval_seconds=interval_s)
                 self._metrics = self._metrics.concat(m)
+
+    def _score_staged(self, block, start, interval_s) -> tuple:
+        """Score a topology epoch's leading drain stages in one batched call,
+        padded to the most stages an epoch can hold (one compiled shape);
+        returns where the steady remainder starts and its burst seed."""
+        from repro.core.simulator import route_metrics_batched
+        from repro.transition import stage_partition
+
+        cc, ev = self.cc, self._staged
+        self._staged = None
+        with obs.span("serve.score.staged", stages=ev.n_stages):
+            spans, seeds, rem_lo, rem_seed = stage_partition(
+                ev, block.shape[0], start,
+                cc.loss.seed if cc.loss is not None else None)
+            idx = [k for k, _, _ in spans]
+            most = min(cc.transition.n_panels,
+                       -(-self.route_step // ev.stage_intervals))
+            self._metrics = self._metrics.concat(route_metrics_batched(
+                [block[lo:hi] for _, lo, hi in spans],
+                ev.stage_w[idx], ev.stage_caps[idx],
+                cc.overload_threshold, backend=cc.backend,
+                loss_cfg=cc.loss, loss_seeds=seeds,
+                interval_seconds=interval_s, pad_to=most))
+        return rem_lo, rem_seed
 
     # ---- finalize ------------------------------------------------------------
 

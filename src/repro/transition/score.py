@@ -23,6 +23,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.core.graph import Fabric
 from repro.core.paths import build_paths, routing_weight_matrices
 from repro.transition.config import TransitionConfig
@@ -56,6 +57,20 @@ class TransitionEval:
     # evals predating the failures subsystem (e.g. hand-built test fixtures).
     steady_w: np.ndarray | None = None  # (2, C, E_d)
     steady_caps: np.ndarray | None = None  # (2, E_d)
+    # PDHG telemetry of the batch [old, new, stages...] (the real elements of
+    # solve_routing_batch's ``stats``, anchors included); None on scipy
+    pdhg_stats: dict | None = None
+
+    @property
+    def pdhg_iters(self) -> int:
+        """PDHG iterations the evaluation ran: every stage of every real
+        element of the batch, plus the cold anchor solves (0 on scipy)."""
+        st = self.pdhg_stats
+        if st is None:
+            return 0
+        return (sum(int(np.sum(st[k]["iters"])) for k in
+                    ("stage1", "stage2", "stage3") if k in st)
+                + sum(st.get("anchor_iters", {}).values()))
 
     @property
     def n_stages(self) -> int:
@@ -101,21 +116,27 @@ class TransitionEval:
 
 
 def score_stage_batch(fabric: Fabric, tms: np.ndarray, capacities: np.ndarray,
-                      delta: float, hedging: bool, sc, cc) -> tuple:
+                      delta: float, hedging: bool, sc, cc,
+                      pad_to: int | None = None) -> tuple:
     """Routing re-solves for a ``(B, E_d)`` batch of capacity vectors.
 
     ``cc.solver_backend == "pdhg"`` solves all elements in one vmapped jitted
-    PDHG call; ``"scipy"`` loops HiGHS LPs.  A *stranded* element — a drain
-    stage leaving some commodity with zero capacity on every candidate path
-    (exactly :func:`proxy_splits` returning None) — gets ``u = inf`` on both
-    backends so the decision rule sees infinite disruption; neither solver
-    reports this itself (scipy's LP turns infeasible, while the PDHG
-    operators treat dead links as unconstrained and return a finite, even
-    zero, ``u``).
+    PDHG call; ``"scipy"`` loops HiGHS LPs.  On PDHG every path over a dead
+    link is left out of its element's routing (a drain can take a trunk's
+    last link down) and the splits are projected onto each commodity's
+    simplex of paths; the batch is padded to ``pad_to`` elements by
+    repeating the first one, so that every batch of a fabric runs the same
+    compiled programs, and the padding is solved and dropped.  A *stranded*
+    element — a drain stage leaving some commodity with zero capacity on
+    every candidate path (exactly :func:`proxy_splits` returning None) — gets
+    ``u = inf`` on both backends so the decision rule sees infinite
+    disruption; neither solver reports this itself (scipy's LP turns
+    infeasible, and PDHG keeps the stranded commodity's dead paths).
 
-    Returns ``(f, u)`` with shapes ``(B, P)`` and ``(B,)``.
+    Returns ``(f, u, stats)`` with shapes ``(B, P)`` and ``(B,)``; ``stats``
+    is the batch's PDHG telemetry for the ``B`` real elements (anchor solves
+    included), None on scipy.
     """
-    from repro import obs
     from repro.core.engine import (_pad_tms, _solve_routing_scipy,
                                    routing_solver_for)
 
@@ -131,15 +152,18 @@ def score_stage_batch(fabric: Fabric, tms: np.ndarray, capacities: np.ndarray,
             solver = routing_solver_for(fabric, cc.k_critical,
                                         cc.pdhg_max_iters, cc.pdhg_tol,
                                         cc.solver_precision)
-            tms_b = np.broadcast_to(_pad_tms(tms, cc.k_critical),
-                                    (b, cc.k_critical, tms.shape[1]))
+            n = max(b, pad_to or 0)
+            caps_n = np.concatenate([caps, np.repeat(caps[:1], n - b, 0)])
+            tms_n = np.broadcast_to(_pad_tms(tms, cc.k_critical),
+                                    (n, cc.k_critical, tms.shape[1]))
             out = solver.solve_routing_batch(
-                np.ascontiguousarray(tms_b), caps, hedging=hedging,
-                deltas=np.full((b,), delta), skip_stage3=sc.skip_stage3)
-            f_b = np.asarray(out["f"], np.float64)
+                np.ascontiguousarray(tms_n), caps_n, hedging=hedging,
+                deltas=np.full((n,), delta), skip_stage3=sc.skip_stage3,
+                anchor=b // 2)
+            f_b = np.asarray(out["f"][:b], np.float64)
             u_b = np.where(stranded, np.inf,
-                           np.asarray(out["u_star"], np.float64))
-            return f_b, u_b
+                           np.asarray(out["u_star"][:b], np.float64))
+            return f_b, u_b, _real_stats(out["stats"], b)
         f_b = np.empty((b, paths.n_paths))
         u_b = np.empty((b,))
         for i in range(b):
@@ -152,7 +176,14 @@ def score_stage_batch(fabric: Fabric, tms: np.ndarray, capacities: np.ndarray,
                     f = np.full((paths.n_paths,), 1.0 / (fabric.n_pods - 1))
                 u = float("inf")
             f_b[i], u_b[i] = f, (float("inf") if stranded[i] else u)
-        return f_b, u_b
+        return f_b, u_b, None
+
+
+def _real_stats(stats: dict, b: int) -> dict:
+    """A padded batch's PDHG telemetry cut to its first ``b`` elements."""
+    return {k: ({f: a[:b] for f, a in v.items()}
+                if k in ("stage1", "stage2", "stage3") else v)
+            for k, v in stats.items()}
 
 
 def evaluate_transition(fabric: Fabric, tms: np.ndarray, n_old: np.ndarray,
@@ -162,7 +193,9 @@ def evaluate_transition(fabric: Fabric, tms: np.ndarray, n_old: np.ndarray,
     """Diff, schedule, and score an old -> new topology change.
 
     Returns None when the change needs no jumper moves (applying it is free
-    — the controller treats that as an unconditional apply).
+    — the controller treats that as an unconditional apply).  The old, new
+    and stage solves run as one batch of ``tcfg.n_panels + 2`` elements
+    whatever the stage count (:func:`score_stage_batch`).
     ``horizon_intervals`` is the window the benefit amortizes over (the
     controller passes its topology reconfiguration period).
 
@@ -176,13 +209,15 @@ def evaluate_transition(fabric: Fabric, tms: np.ndarray, n_old: np.ndarray,
     diff = diff_topologies(fabric.n_pods, n_old, n_new, tcfg.n_panels)
     if diff.total_moves == 0:
         return None
-    order, proxy_worst, proxy_naive = schedule_drains(fabric, tms, diff)
+    with obs.span("transition.schedule", moves=diff.total_moves):
+        order, proxy_worst, proxy_naive = schedule_drains(fabric, tms, diff)
     stage_trunks = stage_trunks_for_order(diff, order)
     stage_caps = np.stack([fabric.capacities(t) for t in stage_trunks])
     caps_b = np.concatenate([fabric.capacities(np.rint(n_old))[None],
                              fabric.capacities(np.rint(n_new))[None],
                              stage_caps])
-    f_b, u_b = score_stage_batch(fabric, tms, caps_b, delta, hedging, sc, cc)
+    f_b, u_b, stats = score_stage_batch(fabric, tms, caps_b, delta, hedging,
+                                        sc, cc, pad_to=tcfg.n_panels + 2)
     paths = build_paths(fabric.n_pods)
     return TransitionEval(
         diff=diff,
@@ -199,6 +234,7 @@ def evaluate_transition(fabric: Fabric, tms: np.ndarray, n_old: np.ndarray,
         horizon_intervals=horizon_intervals,
         steady_w=routing_weight_matrices(paths, f_b[:2]),
         steady_caps=caps_b[:2],
+        pdhg_stats=stats,
     )
 
 

@@ -386,3 +386,22 @@ def test_fleet_valid_mask_equals_pod_removal():
         skip_stage3=True)
     assert float(out_masked["u_star"][0]) == pytest.approx(
         float(out_nat["u_star"][0]), abs=1e-5)
+
+
+def test_resolve_weights_route_nothing_over_dead_links(small_fabric, rng):
+    """The what-if's PDHG re-solve leaves every path over a failed link out
+    of the routing (a dead link is not free capacity)."""
+    from repro.failures.evaluate import resolve_weights
+
+    cap = small_fabric.capacities(
+        realize(small_fabric, uniform_topology(small_fabric))[0])
+    tms = rng.uniform(0.0, 0.3, (1, 3, cap.size)) * cap.mean()
+    masks = np.ones((2, cap.size))
+    dead = rng.choice(cap.size, size=4, replace=False)
+    masks[1, dead] = 0.0
+    cc = dataclasses.replace(CC, solver_backend="pdhg", pdhg_tol=1e-2,
+                             pdhg_max_iters=3000)
+    w, _ = resolve_weights(small_fabric, tms, cap[None], masks,
+                           np.zeros(1), cc, SC)
+    assert np.abs(w[1, 0][:, dead]).max() < 1e-5
+    assert np.abs(w[0, 0][:, dead]).max() > 1e-3  # live, they carry load

@@ -114,6 +114,24 @@ def test_pdhg_stage_compiles_for_v5e(one_chip, solver, stage, warm):
     assert lowered.compile().memory_analysis() is not None
 
 
+GATE_B = 6  # the §4.6 gate's batch: old, new and 4 drain stages
+
+
+@pytest.mark.parametrize("program", ["mlu", "risk", "stretch", "project"])
+def test_gate_batch_compiles_for_v5e(one_chip, solver, program):
+    """The gate's fixed-size batch: the warm stage programs at B = 6 and the
+    projection of the last stage's splits onto the path simplex."""
+    if program == "project":
+        lowered = JaxRoutingSolver._proj_f_batch.lower(
+            solver, _shape(one_chip, (GATE_B, V, V, V)),
+            _shape(one_chip, (GATE_B, V, V, V), jnp.bool_))
+    else:
+        lowered = getattr(JaxRoutingSolver, f"_solve_{program}_batch_warm").lower(
+            solver, *_stage_args(one_chip, GATE_B, True, program))
+        assert "HIGHEST" in lowered.as_text()
+    assert lowered.compile().memory_analysis() is not None
+
+
 @pytest.mark.parametrize("skip_stage3", [False, True],
                          ids=["stages123", "skip_stage3"])
 def test_served_solve_compiles_for_v5e(one_chip, solver, skip_stage3):

@@ -184,7 +184,7 @@ def test_score_stage_batch_stranded_stage_is_infinite(backend):
                      np.zeros(fab.n_directed)])
     cc = dataclasses.replace(CC, solver_backend=backend, k_critical=2,
                              pdhg_max_iters=200)
-    f, u = score_stage_batch(fab, tms, caps, 0.0, False, SC, cc)
+    f, u, _ = score_stage_batch(fab, tms, caps, 0.0, False, SC, cc)
     assert np.isfinite(u[0])
     assert u[1] == float("inf")
     assert f.shape[0] == 2
